@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 from . import hw
 
@@ -97,12 +98,27 @@ def axis_candidates(size: int, granule: int, caps: tuple[int, ...]) -> list[int]
     return sorted(c for c in cands if c > 0)
 
 
+def tile_bytes(shape: tuple[int, ...], elt: int) -> int:
+    """VMEM bytes of one block: its minor two dims occupy whole
+    (sublane, lane) tiles -- (8, 128) for f32, (16, 128) for bf16 -- so a
+    64-wide lane dim costs what a 128-wide one does."""
+    *lead, sub, lane = shape
+    return (math.prod(lead) * round_up(sub, hw.SUBLANE * max(1, 4 // elt))
+            * round_up(lane, hw.LANE) * elt)
+
+
+def streamed_vmem_bytes(elt: int, *blocks: tuple[int, ...]) -> int:
+    """VMEM of operand blocks (inputs and outputs alike) that the Pallas
+    pipeline double-buffers -- the whole modelled working set of the
+    standalone transform kernels and the plain GEMM."""
+    return 2 * sum(tile_bytes(b, elt) for b in blocks)
+
+
 def fused_vmem_bytes(L: int, m: int, bt: int, bc: int, bk: int, elt: int) -> int:
-    v_stream = 2 * L * bt * bc * elt          # double-buffered
-    u_stream = 2 * L * bc * bk * elt
-    acc = L * bt * bk * 4                     # f32 accumulator scratch
-    out = 2 * bt * m * m * bk * elt
-    return v_stream + u_stream + acc + out
+    streams = streamed_vmem_bytes(elt, (L, bt, bc), (L, bc, bk),
+                                  (bt, m * m, bk))
+    acc = tile_bytes((L, bt, bk), 4)          # f32 accumulator scratch
+    return streams + acc
 
 
 def e2e_vmem_bytes(L: int, m: int, Cp: int, bt: int, bc: int, bk: int,
@@ -110,15 +126,26 @@ def e2e_vmem_bytes(L: int, m: int, Cp: int, bt: int, bc: int, bk: int,
     """VMEM working set of the end-to-end fused kernel (wino_fused_e2e).
 
     The B^T d B prologue replaces the streamed V operand with (a) the
-    streamed raw-tile block d and (b) a full-C f32 V-cache that lets the
-    transform run once per tile block and be reused across every K block.
+    streamed raw-tile block d and (b) a full-C f32 V-cache, laid out
+    (Cp/bc, L, bt, bc), that lets the transform run once per tile block
+    and be reused across every K block.
     """
-    d_stream = 2 * bt * L * bc * elt          # double-buffered raw tiles
-    u_stream = 2 * L * bc * bk * elt
-    v_cache = L * bt * Cp * 4                 # f32, resident across K blocks
-    acc = L * bt * bk * 4
-    out = 2 * bt * m * m * bk * elt
-    return d_stream + u_stream + v_cache + acc + out
+    streams = streamed_vmem_bytes(elt, (bt, L, bc), (L, bc, bk),
+                                  (bt, m * m, bk))
+    v_cache = tile_bytes((Cp // bc, L, bt, bc), 4)   # resident across K
+    acc = tile_bytes((L, bt, bk), 4)
+    return streams + v_cache + acc
+
+
+def vmem_limit_bytes(model_bytes: int) -> int:
+    """Scoped-VMEM limit a kernel passes to the compiler for a modelled
+    working set of ``model_bytes``: the model plus headroom for the
+    compiler's own temporaries (``hw.VMEM_HEADROOM_*``), capped at the
+    physical VMEM.  ``choose_blocks`` never selects blocks over
+    ``hw.VMEM_BUDGET``, under which the cap never cuts into the headroom."""
+    headroom = max(int(model_bytes * hw.VMEM_HEADROOM_FRAC),
+                   hw.VMEM_HEADROOM_MIN)
+    return min(hw.VMEM_BYTES, model_bytes + headroom)
 
 
 def hbm_traffic(L: int, m: int, T: int, C: int, K: int, bt: int, bk: int, elt: int,
@@ -166,15 +193,13 @@ def hbm_traffic_e2e(L: int, m: int, T: int, C: int, K: int, bt: int, bc: int,
 def bwd_fused_vmem_bytes(L: int, m: int, Kp: int, bt: int, bc: int, bk: int,
                          elt: int) -> int:
     """VMEM working set of the single-pass fused backward kernel."""
-    d_stream = 2 * bt * L * bc * elt          # double-buffered raw tiles
-    gy_stream = 2 * bt * m * m * bk * elt     # double-buffered gy tiles
-    u_stream = 2 * L * bc * bk * elt
-    v_slice = L * bt * bc * 4                 # shared V-cache slice, f32
-    do_scratch = L * bt * bk * 4              # dO^ (gy transformed once/step)
-    dd_out = 2 * bt * L * bc * 4              # dV accumulator == dd out block
-    du_out = L * bc * Kp * 4                  # full-K dU block, resident per C
-    return (d_stream + gy_stream + u_stream + v_slice + do_scratch
-            + dd_out + du_out)
+    streams = (streamed_vmem_bytes(elt, (bt, L, bc), (bt, m * m, bk),
+                                   (L, bc, bk))      # d, gy, U
+               # outputs: dd (the dV accumulator) and the full-K dU block
+               + streamed_vmem_bytes(4, (bt, L, bc), (L, bc, Kp)))
+    v_slice = tile_bytes((L, bt, bc), 4)      # shared V-cache slice, f32
+    do_scratch = tile_bytes((L, bt, bk), 4)   # dO^ (gy transformed once/step)
+    return streams + v_slice + do_scratch
 
 
 def hbm_traffic_bwd_fused(L: int, m: int, T: int, C: int, K: int, bt: int,
@@ -294,9 +319,8 @@ def choose_blocks(
     ``pipeline`` selects the VMEM constraint and traffic objective:
     "fused" (default) and "nonfused" share the streamed-V working set;
     "fused_e2e" adds the full-C V-cache and minimizes the single-pass
-    traffic.  Returns None for "fused_e2e" when no candidate fits the
-    budget (the V-cache is a hard constraint there); the two-stage
-    pipelines keep the legacy minimum-aligned-blocks fallback.
+    traffic.  Returns None when no candidate fits the budget: a working
+    set over it would be refused by the compiler, so it is never chosen.
     """
     assert pipeline in PIPELINES, pipeline
     a = m + r - 1
@@ -334,14 +358,6 @@ def choose_blocks(
                     or (obj == best_obj and (bt * bk) > (best.block_t * best.block_k))
                 ):
                     best = cand
-    if best is None:
-        if pipeline == "fused_e2e":
-            return None  # V-cache cannot fit: e2e ineligible at this shape
-        bt = 64
-        bc = min(128, round_up(C, 8))
-        bk = min(128, round_up(K, 8))
-        best = _make_config(L, m, T, C, K, bt, bc, bk, elt_bytes,
-                            fused_vmem_bytes(L, m, bt, bc, bk, elt_bytes))
     return best
 
 

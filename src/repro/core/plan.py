@@ -328,9 +328,7 @@ def grad_kernel_blocks(C: int, T: int, K: int, m: int, r: int,
     the GEMM extents, not N/H/W): rows=C, contraction=T, cols=K mapped onto
     ``choose_blocks``' (T, C, K) slots.
     """
-    cfg = blocking.choose_blocks(C, T, K, m, r, elt_bytes, pipeline="nonfused")
-    assert cfg is not None
-    return cfg
+    return kernel_blocks(C, T, K, m, r, elt_bytes, pipeline="nonfused")
 
 
 def bwd_kernel_blocks(T: int, C: int, K: int, m: int, r: int,
@@ -347,14 +345,16 @@ def kernel_blocks(T: int, C: int, K: int, m: int, r: int, elt_bytes: int,
     """Blocking decision for an already-tiled problem -- the plan-layer
     entry point for ``kernels/ops.py`` (which sees T, not N/H/W).
 
-    An explicit "fused_e2e" request whose V-cache cannot fit the VMEM
-    budget falls back to blocks chosen under the two-stage constraint: the
-    kernel still runs (interpret mode has no real VMEM wall); ``plan``
-    itself never *selects* e2e in that regime.
+    Raises ``ValueError`` when no blocking of ``pipeline`` fits the VMEM
+    budget (for "fused_e2e", a full-C V-cache too large at every tile
+    block): the compiler would refuse the kernel.  ``plan`` never selects
+    a pipeline in that regime.
     """
     cfg = blocking.choose_blocks(T, C, K, m, r, elt_bytes, pipeline=pipeline)
     if cfg is None:
-        cfg = blocking.choose_blocks(T, C, K, m, r, elt_bytes, pipeline="fused")
+        raise ValueError(
+            f"no {pipeline} blocking of T={T} C={C} K={K} F({m},{r}) fits "
+            f"the {blocking.hw.VMEM_BUDGET} B VMEM budget")
     return cfg
 
 

@@ -21,12 +21,21 @@ Winograd-domain:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import tiles as tiling
 from .transforms import grad_transform_arrays, transform_arrays
+
+#: Precision of every f32 contraction of the Winograd path -- this
+#: reference, the mesh path and the Pallas kernels.  On a TPU an f32 dot at
+#: default precision takes bf16 passes (Mosaic's too), and the transforms
+#: amplify operand rounding by O(2^m), so all of them ask for full f32.
+PRECISION = jax.lax.Precision.HIGHEST
+_einsum = functools.partial(jnp.einsum, precision=PRECISION)
 
 
 def _consts(m: int, r: int, dtype=jnp.float32):
@@ -59,7 +68,7 @@ def input_transform(tiles: jax.Array, m: int, r: int) -> jax.Array:
     """
     _, _, BT = _consts(m, r, tiles.dtype)
     # d: (T, i, j, C);  V[x,y] = sum_ij BT[x,i] BT[y,j] d[i,j]
-    v = jnp.einsum("xi,tijc,yj->xytc", BT, tiles, BT)
+    v = _einsum("xi,tijc,yj->xytc", BT, tiles, BT)
     a = BT.shape[0]
     return v.reshape(a * a, *v.shape[2:])  # (L, T, C)
 
@@ -67,14 +76,14 @@ def input_transform(tiles: jax.Array, m: int, r: int) -> jax.Array:
 def filter_transform(w: jax.Array, m: int, r: int) -> jax.Array:
     """(r, r, C, K) -> U (L, C, K) : U = G g G^T."""
     _, G, _ = _consts(m, r, w.dtype)
-    u = jnp.einsum("xi,ijck,yj->xyck", G, w, G)
+    u = _einsum("xi,ijck,yj->xyck", G, w, G)
     a = G.shape[0]
     return u.reshape(a * a, *u.shape[2:])
 
 
 def batched_gemm(V: jax.Array, U: jax.Array) -> jax.Array:
     """O^[l] = V[l] @ U[l] -- the paper's Eq. (4) as an L-batched GEMM."""
-    return jnp.einsum("ltc,lck->ltk", V, U)
+    return _einsum("ltc,lck->ltk", V, U)
 
 
 def tewmm(V: jax.Array, U: jax.Array) -> jax.Array:
@@ -90,7 +99,7 @@ def output_transform(O_hat: jax.Array, m: int, r: int) -> jax.Array:
     AT, _, _ = _consts(m, r, O_hat.dtype)
     a = m + r - 1
     o = O_hat.reshape(a, a, *O_hat.shape[1:])  # (x, y, T, K)
-    return jnp.einsum("ix,xytk,jy->tijk", AT, o, AT)
+    return _einsum("ix,xytk,jy->tijk", AT, o, AT)
 
 
 # ----------------------- filter-gradient pipeline -----------------------
@@ -117,14 +126,14 @@ def grad_output_transform(gy_tiles: jax.Array, m: int, r: int) -> jax.Array:
     plays the role of the filter in the gradient convolution.
     """
     _, Gg, _ = _grad_consts(m, r, gy_tiles.dtype)
-    g = jnp.einsum("xi,tijk,yj->xytk", Gg, gy_tiles, Gg)
+    g = _einsum("xi,tijk,yj->xytk", Gg, gy_tiles, Gg)
     a = Gg.shape[0]
     return g.reshape(a * a, *g.shape[2:])  # (L, T, K)
 
 
 def grad_gemm(V: jax.Array, Gy: jax.Array) -> jax.Array:
     """dU[l] = V[l]^T @ Gy[l] -- the gradient GEMM, contraction over T."""
-    return jnp.einsum("ltc,ltk->lck", V, Gy)
+    return _einsum("ltc,ltk->lck", V, Gy)
 
 
 def filter_grad_inverse(dU: jax.Array, m: int, r: int) -> jax.Array:
@@ -132,7 +141,7 @@ def filter_grad_inverse(dU: jax.Array, m: int, r: int) -> jax.Array:
     ATg, _, _ = _grad_consts(m, r, dU.dtype)
     a = m + r - 1
     du = dU.reshape(a, a, *dU.shape[1:])  # (x, y, C, K)
-    return jnp.einsum("ux,xyck,vy->uvck", ATg, du, ATg)
+    return _einsum("ux,xyck,vy->uvck", ATg, du, ATg)
 
 
 # ----------------------- adjoint (single-pass) stages -----------------------
@@ -160,7 +169,7 @@ def output_transform_adjoint(gy_tiles: jax.Array, m: int, r: int) -> jax.Array:
     dO^ = A gy A^T with A = (A^T)^T -- gy plays the role O^ played forward.
     """
     AT, _, _ = _consts(m, r, gy_tiles.dtype)
-    do = jnp.einsum("ix,tijk,jy->xytk", AT, gy_tiles, AT)
+    do = _einsum("ix,tijk,jy->xytk", AT, gy_tiles, AT)
     a = AT.shape[1]
     return do.reshape(a * a, *do.shape[2:])  # (L, T, K)
 
@@ -174,7 +183,7 @@ def input_transform_adjoint(dV: jax.Array, m: int, r: int) -> jax.Array:
     _, _, BT = _consts(m, r, dV.dtype)
     a = BT.shape[0]
     dv = dV.reshape(a, a, *dV.shape[1:])  # (x, y, T, C)
-    return jnp.einsum("xi,xytc,yj->tijc", BT, dv, BT)
+    return _einsum("xi,xytc,yj->tijc", BT, dv, BT)
 
 
 def filter_transform_adjoint(dU: jax.Array, m: int, r: int) -> jax.Array:
@@ -186,7 +195,7 @@ def filter_transform_adjoint(dU: jax.Array, m: int, r: int) -> jax.Array:
     _, G, _ = _consts(m, r, dU.dtype)
     a = G.shape[0]
     du = dU.reshape(a, a, *dU.shape[1:])  # (x, y, C, K)
-    return jnp.einsum("xu,xyck,yv->uvck", G, du, G)
+    return _einsum("xu,xyck,yv->uvck", G, du, G)
 
 
 def winograd_backward_reference(
@@ -212,8 +221,8 @@ def winograd_backward_reference(
     U = filter_transform(w, m, r)                        # (L, C, K)
     gy_t = tiling.extract_output_tiles(gy, m, tH, tW)    # (T, m, m, K)
     dO = output_transform_adjoint(gy_t, m, r)            # gy transformed ONCE
-    dV = jnp.einsum("ltk,lck->ltc", dO, U)               # dx GEMM (red = K)
-    dU = jnp.einsum("ltc,ltk->lck", V, dO)               # dw GEMM (red = T)
+    dV = _einsum("ltk,lck->ltc", dO, U)                  # dx GEMM (red = K)
+    dU = _einsum("ltc,ltk->lck", V, dO)                  # dw GEMM (red = T)
     dd = input_transform_adjoint(dV, m, r)               # (T, a, a, C)
     dx = tiling.overlap_add_tiles(dd, N, tH, tW, m, r, H, W, pad)
     dw = filter_transform_adjoint(dU, m, r)
@@ -301,7 +310,7 @@ def im2col_conv2d(x: jax.Array, w: jax.Array, *, pad: int = 0) -> jax.Array:
     xp, tH, tW, P, Q = tiling.pad_for_tiles(x, 1, r, pad)
     t6 = tiling.extract_tiles(xp, 1, r, tH, tW)  # m=1: every output position
     d = t6.reshape(N * tH * tW, r * r * C)
-    y = d @ w.reshape(r * r * C, -1)
+    y = jnp.matmul(d, w.reshape(r * r * C, -1), precision=PRECISION)
     y = y.reshape(N, tH, tW, 1, 1, -1).reshape(N, tH, tW, -1)
     return y[:, :P, :Q, :]
 
@@ -322,10 +331,10 @@ def winograd_conv1d_reference(
     w = w.astype(jnp.float32)
     xp, t, P = tiling.pad_for_tiles_1d(x, m, r, pad)
     d = tiling.extract_tiles_1d(xp, m, r, t)            # (N, t, alpha, C)
-    V = jnp.einsum("xi,ntic->xntc", BT, d)              # (alpha, N, t, C)
-    U = jnp.einsum("xi,ick->xck", G, w)                 # (alpha, C, K)
-    O_hat = jnp.einsum("xntc,xck->xntk", V, U)
-    y = jnp.einsum("mx,xntk->ntmk", AT, O_hat)          # (N, t, m, K)
+    V = _einsum("xi,ntic->xntc", BT, d)                 # (alpha, N, t, C)
+    U = _einsum("xi,ick->xck", G, w)                    # (alpha, C, K)
+    O_hat = _einsum("xntc,xck->xntk", V, U)
+    y = _einsum("mx,xntk->ntmk", AT, O_hat)             # (N, t, m, K)
     y = y.reshape(x.shape[0], t * m, -1)[:, :P, :]
     return y.astype(in_dtype)
 

@@ -1,4 +1,8 @@
-"""Pallas TPU kernels for the Winograd pipeline (validated in interpret mode).
+"""Pallas TPU kernels for the Winograd pipeline.
+
+Validated in interpret mode on the CPU, compiled for a described v5e by
+``tests/test_tpu_compile.py``, and run compiled on the chip by
+``chip_smoke.py``.
 
 Each kernel module pairs with an oracle in ``ref.py``; ``ops.py`` holds the
 jit'd wrappers that compose them into full convolutions.

@@ -10,9 +10,15 @@ playing the role of NEON's theta-wide vectors.
 
 from __future__ import annotations
 
+import collections
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core import blocking
 
 
 def apply_matrix(mat: np.ndarray, vecs: list[jax.Array]) -> list[jax.Array]:
@@ -64,6 +70,24 @@ def pad_axis_to(x: jax.Array, axis: int, size: int) -> jax.Array:
     cfg = [(0, 0)] * x.ndim
     cfg[axis] = (0, pad)
     return jnp.pad(x, cfg)
+
+
+def compiler_params(vmem_bytes: int) -> pltpu.CompilerParams:
+    """Mosaic parameters for a kernel whose blocking model puts its VMEM
+    working set at ``vmem_bytes``: the scoped-VMEM limit is derived from
+    that model (``blocking.vmem_limit_bytes``), never a constant."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=blocking.vmem_limit_bytes(vmem_bytes))
+
+
+_TPU_KERNEL = re.compile(
+    r'%([A-Za-z_]\w*)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"')
+
+
+def tpu_kernel_calls(hlo_text: str) -> collections.Counter:
+    """Compiled Pallas kernels in TPU HLO text, counted by kernel name
+    (every ``pallas_call`` here passes its ``name``)."""
+    return collections.Counter(_TPU_KERNEL.findall(hlo_text))
 
 
 def default_interpret() -> bool:

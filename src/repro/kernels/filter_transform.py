@@ -21,8 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.blocking import streamed_vmem_bytes
 from repro.core.transforms import transform_arrays
-from .common import apply_matrix, default_interpret
+from .common import apply_matrix, compiler_params, default_interpret
 
 
 def _kernel(g_ref, u_ref, *, m: int, r: int, G):
@@ -65,5 +66,8 @@ def filter_transform(
         in_specs=[pl.BlockSpec((rr, block_c, block_k), lambda c, k: (0, c, k))],
         out_specs=pl.BlockSpec((L, block_c, block_k), lambda c, k: (0, c, k)),
         out_shape=jax.ShapeDtypeStruct((L, C, K), w_flat.dtype),
+        compiler_params=compiler_params(streamed_vmem_bytes(
+            w_flat.dtype.itemsize, (rr, block_c, block_k), (L, block_c, block_k))),
+        name="filter_transform",
         interpret=interpret,
     )(w_flat)

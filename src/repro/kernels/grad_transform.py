@@ -20,8 +20,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.blocking import streamed_vmem_bytes
 from repro.core.transforms import grad_transform_arrays
-from .common import apply_matrix, default_interpret
+from .common import apply_matrix, compiler_params, default_interpret
 
 
 def _kernel(gy_ref, out_ref, *, m: int, r: int, Gg):
@@ -66,5 +67,8 @@ def grad_output_transform(
         in_specs=[pl.BlockSpec((block_t, mm, block_k), lambda t, k: (t, 0, k))],
         out_specs=pl.BlockSpec((L, block_t, block_k), lambda t, k: (0, t, k)),
         out_shape=jax.ShapeDtypeStruct((L, T, K), gy_flat.dtype),
+        compiler_params=compiler_params(streamed_vmem_bytes(
+            gy_flat.dtype.itemsize, (block_t, mm, block_k), (L, block_t, block_k))),
+        name="grad_output_transform",
         interpret=interpret,
     )(gy_flat)

@@ -22,8 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.blocking import streamed_vmem_bytes
 from repro.core.transforms import transform_arrays
-from .common import apply_matrix, default_interpret
+from .common import apply_matrix, compiler_params, default_interpret
 
 
 def _kernel(d_ref, v_ref, *, m: int, r: int, BT):
@@ -67,5 +68,8 @@ def input_transform(
         in_specs=[pl.BlockSpec((block_t, L, block_c), lambda t, c: (t, 0, c))],
         out_specs=pl.BlockSpec((L, block_t, block_c), lambda t, c: (0, t, c)),
         out_shape=jax.ShapeDtypeStruct((L, T, C), d_flat.dtype),
+        compiler_params=compiler_params(streamed_vmem_bytes(
+            d_flat.dtype.itemsize, (block_t, L, block_c), (L, block_t, block_c))),
+        name="input_transform",
         interpret=interpret,
     )(d_flat)

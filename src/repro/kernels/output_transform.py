@@ -17,8 +17,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.blocking import streamed_vmem_bytes
 from repro.core.transforms import transform_arrays
-from .common import apply_matrix, default_interpret
+from .common import apply_matrix, compiler_params, default_interpret
 
 
 def _kernel(o_ref, y_ref, *, m: int, r: int, AT):
@@ -65,5 +66,9 @@ def output_transform(
         in_specs=[pl.BlockSpec((L, block_t, block_k), lambda t, k: (0, t, k))],
         out_specs=pl.BlockSpec((block_t, m * m, block_k), lambda t, k: (t, 0, k)),
         out_shape=jax.ShapeDtypeStruct((T, m * m, K), out_dtype),
+        compiler_params=compiler_params(streamed_vmem_bytes(
+            max(O_hat.dtype.itemsize, jnp.dtype(out_dtype).itemsize),
+            (L, block_t, block_k), (block_t, m * m, block_k))),
+        name="output_transform",
         interpret=interpret,
     )(O_hat)

@@ -32,8 +32,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.blocking import fused_vmem_bytes
 from repro.core.transforms import transform_arrays
-from .common import apply_matrix, default_interpret
+from repro.core.winograd import PRECISION
+from .common import (apply_matrix, compiler_params,
+                     default_interpret)
 
 
 def _kernel(v_ref, u_ref, y_ref, acc_ref, *, m: int, r: int, AT, n_c: int):
@@ -49,7 +52,8 @@ def _kernel(v_ref, u_ref, y_ref, acc_ref, *, m: int, r: int, AT, n_c: int):
     # (bt, bc) x (bc, bk) MXU matmul.
     for l in range(L):
         acc_ref[l, :, :] += jnp.dot(
-            v_ref[l, :, :], u_ref[l, :, :], preferred_element_type=jnp.float32
+            v_ref[l, :, :], u_ref[l, :, :], preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
 
     @pl.when(c_idx == n_c - 1)
@@ -106,5 +110,8 @@ def wino_fused(
         out_specs=pl.BlockSpec((block_t, m * m, block_k), lambda t, k, c: (t, 0, k)),
         out_shape=jax.ShapeDtypeStruct((T, m * m, K), out_dtype),
         scratch_shapes=[pltpu.VMEM((L, block_t, block_k), jnp.float32)],
+        compiler_params=compiler_params(fused_vmem_bytes(
+            L, m, block_t, block_c, block_k, V.dtype.itemsize)),
+        name="wino_fused",
         interpret=interpret,
     )(V, U)

@@ -29,7 +29,8 @@ Grid: (C/bc, T/bt, K/bk) -- C OUTERMOST, K innermost:
     constant over the whole (t, k) sweep of one C block -- written back
     exactly once per C block, dU touches HBM once total.
 
-VMEM working set is ``blocking.bwd_fused_vmem_bytes``; traffic is
+VMEM working set is ``blocking.bwd_fused_vmem_bytes`` (it also sets the
+kernel's scoped-VMEM limit); traffic is
 ``blocking.hbm_traffic_bwd_fused``.  Feasibility (the resident dU block is
 the hard constraint) is decided by ``plan.bwd_kernel_blocks``; infeasible
 shapes take the two-pass backward.
@@ -44,8 +45,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.blocking import bwd_fused_vmem_bytes
 from repro.core.transforms import transform_arrays
-from .common import default_interpret, transform_2d
+from repro.core.winograd import PRECISION
+from .common import (compiler_params, default_interpret,
+                     transform_2d)
 
 
 def _kernel(d_ref, gy_ref, u_ref, dd_ref, du_ref, v_ref, do_ref, *,
@@ -54,6 +58,12 @@ def _kernel(d_ref, gy_ref, u_ref, dd_ref, du_ref, v_ref, do_ref, *,
     L = a * a
     t_idx = pl.program_id(1)
     k_idx = pl.program_id(2)
+    # K columns of the resident full-K dU block that this step owns.  With
+    # one K block that is the whole lane axis (K may be narrower than a
+    # lane); with several, block_k is a multiple of 128, which the hint
+    # lets Mosaic prove for the dynamic lane offset.
+    k_cols = (slice(None) if n_k == 1 else
+              pl.ds(pl.multiple_of(k_idx * block_k, block_k), block_k))
 
     # ---- prologue: B^T d B into the shared V-slice, once per (c, t) ----
     @pl.when(k_idx == 0)
@@ -80,7 +90,7 @@ def _kernel(d_ref, gy_ref, u_ref, dd_ref, du_ref, v_ref, do_ref, *,
 
     @pl.when(t_idx == 0)
     def _init_du():
-        du_ref[:, :, pl.ds(k_idx * block_k, block_k)] = jnp.zeros(
+        du_ref[:, :, k_cols] = jnp.zeros(
             (L, du_ref.shape[1], block_k), jnp.float32)
 
     # ---- dual GEMMs against the shared V-slice / dO^ ----
@@ -90,12 +100,14 @@ def _kernel(d_ref, gy_ref, u_ref, dd_ref, du_ref, v_ref, do_ref, *,
         dd_ref[:, l, :] += jax.lax.dot_general(
             dg, u_ref[l, :, :],
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=PRECISION,
             preferred_element_type=jnp.float32,
         )
         # dw side: dU[l] += V[l]^T @ dO^[l]   (contraction over T)
-        du_ref[l, :, pl.ds(k_idx * block_k, block_k)] += jax.lax.dot_general(
+        du_ref[l, :, k_cols] += jax.lax.dot_general(
             v_ref[l, :, :], dg,
             dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=PRECISION,
             preferred_element_type=jnp.float32,
         )
 
@@ -176,5 +188,8 @@ def wino_fused_bwd(
             pltpu.VMEM((L, block_t, block_c), jnp.float32),   # V-slice
             pltpu.VMEM((L, block_t, block_k), jnp.float32),   # dO^
         ],
+        compiler_params=compiler_params(bwd_fused_vmem_bytes(
+            L, m, K, block_t, block_c, block_k, d.dtype.itemsize)),
+        name="wino_fused_bwd",
         interpret=interpret,
     )(d, gy_t, U)

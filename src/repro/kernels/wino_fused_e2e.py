@@ -26,7 +26,13 @@ in ``repro.core.blocking``.
 
 VMEM working set (f32): 2*bt*L*bc (d, double-buffered) + 2*L*bc*bk (U)
 + L*bt*C (V-cache) + L*bt*bk (acc) + 2*bt*m^2*bk (out); the blocking
-model's "fused_e2e" constraint (``e2e_vmem_bytes``) gates eligibility.
+model's "fused_e2e" constraint (``e2e_vmem_bytes``) gates eligibility and
+sets the kernel's scoped-VMEM limit.
+
+The V-cache is laid out (C/bc, L, bt, bc): the C-block index selects a
+leading, untiled axis, so every cache access is a whole-lane slab and the
+kernel compiles for any bc, including C blocks narrower than a lane
+(C=64 layers, the C=3 stem padded to 8).
 """
 
 from __future__ import annotations
@@ -38,12 +44,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.blocking import e2e_vmem_bytes
 from repro.core.transforms import transform_arrays
-from .common import apply_matrix, default_interpret
+from repro.core.winograd import PRECISION
+from .common import (apply_matrix, compiler_params,
+                     default_interpret)
 
 
 def _kernel(d_ref, u_ref, y_ref, vcache_ref, acc_ref, *, m: int, r: int,
-            AT, BT, n_c: int, block_c: int):
+            AT, BT, n_c: int):
     a = m + r - 1
     L = a * a
     k_idx = pl.program_id(1)
@@ -62,14 +71,15 @@ def _kernel(d_ref, u_ref, y_ref, vcache_ref, acc_ref, *, m: int, r: int,
         for x in range(a):
             outs = apply_matrix(BT, [tmp[j][x] for j in range(a)])
             for y in range(a):
-                vcache_ref[x * a + y, :, pl.ds(c_idx * block_c, block_c)] = outs[y]
+                vcache_ref[c_idx, x * a + y, :, :] = outs[y]
 
     # ---- L-batched GEMM accumulation, V served from the VMEM cache ----
     for l in range(L):
         acc_ref[l, :, :] += jnp.dot(
-            vcache_ref[l, :, pl.ds(c_idx * block_c, block_c)],
+            vcache_ref[c_idx, l, :, :],
             u_ref[l, :, :],
             preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
 
     # ---- epilogue: A^T (.) A inverse transform on the last C step ----
@@ -118,8 +128,7 @@ def wino_fused_e2e(
 
     grid = (T // block_t, K // block_k, n_c)
     return pl.pallas_call(
-        functools.partial(_kernel, m=m, r=r, AT=AT, BT=BT, n_c=n_c,
-                          block_c=block_c),
+        functools.partial(_kernel, m=m, r=r, AT=AT, BT=BT, n_c=n_c),
         grid=grid,
         in_specs=[
             # d collapses to block (t, 0, 0) once k > 0: the V-cache serves
@@ -132,8 +141,11 @@ def wino_fused_e2e(
         out_specs=pl.BlockSpec((block_t, m * m, block_k), lambda t, k, c: (t, 0, k)),
         out_shape=jax.ShapeDtypeStruct((T, m * m, K), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((L, block_t, C), jnp.float32),
+            pltpu.VMEM((n_c, L, block_t, block_c), jnp.float32),
             pltpu.VMEM((L, block_t, block_k), jnp.float32),
         ],
+        compiler_params=compiler_params(e2e_vmem_bytes(
+            L, m, C, block_t, block_c, block_k, d.dtype.itemsize)),
+        name="wino_fused_e2e",
         interpret=interpret,
     )(d, U)

@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import default_interpret
+from repro.core.blocking import streamed_vmem_bytes
+from repro.core.winograd import PRECISION
+from .common import compiler_params, default_interpret
 
 
 def _kernel(v_ref, u_ref, o_ref, *, transpose_lhs: bool):
@@ -47,11 +49,13 @@ def _kernel(v_ref, u_ref, o_ref, *, transpose_lhs: bool):
         part = jax.lax.dot_general(
             v_ref[0, :, :], u_ref[0, :, :],
             dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=PRECISION,
             preferred_element_type=jnp.float32,
         )
     else:
         part = jnp.dot(
-            v_ref[0, :, :], u_ref[0, :, :], preferred_element_type=jnp.float32
+            v_ref[0, :, :], u_ref[0, :, :], preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
     o_ref[0, :, :] += part.astype(o_ref.dtype)
 
@@ -104,5 +108,8 @@ def wino_gemm(
         ],
         out_specs=pl.BlockSpec((1, block_t, block_k), lambda l, t, k, c: (l, t, k)),
         out_shape=jax.ShapeDtypeStruct((L, T, K), jnp.float32),
+        compiler_params=compiler_params(streamed_vmem_bytes(
+            4, (block_t, block_c), (block_c, block_k), (block_t, block_k))),
+        name="wino_gemm",
         interpret=interpret,
     )(V, U)

@@ -26,15 +26,17 @@ import os
 
 import jax
 
-from repro.parallel.compat import axis_types_auto, make_mesh
-
 HOST_DEVICE_FLAG = "--xla_force_host_platform_device_count"
+
+
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=axis_types_auto(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(*, dp: int | None = None, tp: int = 1):
@@ -42,7 +44,7 @@ def make_host_mesh(*, dp: int | None = None, tp: int = 1):
     n = jax.device_count()
     dp = dp or (n // tp)
     assert dp * tp <= n, (dp, tp, n)
-    return make_mesh((dp, tp), ("data", "model"), axis_types=axis_types_auto(2))
+    return jax.make_mesh((dp, tp), ("data", "model"), axis_types=_auto(2))
 
 
 def request_host_devices(n: int) -> None:
@@ -71,5 +73,5 @@ def host_mesh(n: int = 8, *, tp: int = 2):
             f"process with XLA_FLAGS={HOST_DEVICE_FLAG}={n} (see "
             f"tests/conftest.py REPRO_HOST_DEVICES)")
     assert n % tp == 0, (n, tp)
-    return make_mesh((n // tp, tp), ("data", "model"),
-                     axis_types=axis_types_auto(2))
+    return jax.make_mesh((n // tp, tp), ("data", "model"),
+                         axis_types=_auto(2))

@@ -19,9 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import build
-from repro.parallel.compat import set_mesh
 from repro.serve import (ContinuousBatchingScheduler, ServeEngine,
                          poisson_schedule, run_uniform_batches)
 
@@ -71,12 +71,13 @@ def main() -> int:
                          "baseline) instead of one uniform batch")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
     api = build(cfg)
     mesh = make_host_mesh(tp=args.tp)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.PRNGKey(0))
         engine = ServeEngine(api, params,
                              max_len=args.prompt_len + args.new_tokens)

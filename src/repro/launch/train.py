@@ -16,10 +16,10 @@ import jax
 
 from repro import configs
 from repro.data import SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.workloads import make_optimizer_for
 from repro.models.api import build
-from repro.parallel.compat import set_mesh
 from repro.train import Trainer, TrainerConfig, build_train_step, init_state
 
 
@@ -37,6 +37,7 @@ def main() -> int:
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
@@ -60,7 +61,7 @@ def main() -> int:
                            global_batch=args.batch, seed=args.seed,
                            extras=extras)
     step_fn = build_train_step(api, opt, microbatches=args.microbatches)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_state(api, opt, jax.random.PRNGKey(args.seed))
         trainer = Trainer(step_fn, pipe, TrainerConfig(
             total_steps=args.steps, ckpt_dir=args.ckpt_dir,

@@ -68,12 +68,8 @@ def mesh_view(mesh, mode: str):
 
 
 def _mesh_of(devices, names):
-    from repro.parallel.compat import axis_types_auto
-
-    types = axis_types_auto(len(names))
-    if types is None:
-        return jax.sharding.Mesh(devices, names)
-    return jax.sharding.Mesh(devices, names, axis_types=types)
+    return jax.sharding.Mesh(
+        devices, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def _lm_plan(cfg: ModelConfig, shape: str):
@@ -292,17 +288,15 @@ def run_cnn_workload(wl: CNNWorkload, *, steps: int = 8,
 def lower_workload(wl: Workload, mesh=None):
     """jit + lower under the mesh context; returns the Lowered object.
 
-    ``compat.set_mesh`` (not a bare ``with mesh:`` on new JAX) -- only the
+    ``jax.set_mesh`` (not a bare ``with mesh:``) -- only the
     ambient-mesh context makes in-model ``with_sharding_constraint`` calls
     (and the vocab-parallel shard_map) resolve during tracing.
     """
-    from repro.parallel.compat import set_mesh
-
     fn = jax.jit(
         wl.fn,
         in_shardings=wl.in_shardings,
         out_shardings=wl.out_shardings,
         donate_argnums=wl.donate,
     )
-    with set_mesh(wl.mesh if wl.mesh is not None else mesh):
+    with jax.set_mesh(wl.mesh if wl.mesh is not None else mesh):
         return fn.lower(*wl.abstract_args)

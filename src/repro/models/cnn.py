@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core import conv2d
 from repro.core.conv import Algorithm
+from repro.core.winograd import PRECISION
 
 from .config import CNNConfig, ConvLayerSpec
 
@@ -57,6 +58,11 @@ def avgpool_global(x: jax.Array) -> jax.Array:
     return jnp.mean(x, axis=(1, 2))
 
 
+def _head(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Classifier logits in f32, at the full f32 precision of the convs."""
+    return jnp.matmul(x, w, precision=PRECISION).astype(jnp.float32)
+
+
 # ------------------------------- VGG-16 -------------------------------
 
 VGG16_PLAN = [  # (n_convs, channels) per stage; maxpool between stages
@@ -88,7 +94,7 @@ def vgg16_forward(params: Params, x: jax.Array, *,
             x = conv_block(p, x, pad=1, algorithm=algorithm)
         x = maxpool2(x)
     x = avgpool_global(x)
-    return (x @ params["head"]).astype(jnp.float32)
+    return _head(x, params["head"])
 
 
 # ------------------------------ ResNet-50 ------------------------------
@@ -156,7 +162,7 @@ def resnet50_forward(params: Params, x: jax.Array, *,
             stride = 2 if (bi == 0 and si > 0) else 1
             x = _bottleneck(p, x, stride=stride, algorithm=algorithm)
     x = avgpool_global(x)
-    return (x @ params["head"]).astype(jnp.float32)
+    return _head(x, params["head"])
 
 
 # ------------------------------ FusionNet ------------------------------

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel import axis_size, constrain
-from repro.parallel.compat import get_abstract_mesh, shard_map
 
 from .config import ModelConfig
 
@@ -403,7 +402,7 @@ def _attn_decode_splitk(q, k, v, *, causal_offset, window, softcap,
     """
     from jax.sharding import PartitionSpec as P
 
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -456,7 +455,7 @@ def _attn_decode_splitk(q, k, v, *, causal_offset, window, softcap,
         return jnp.einsum("bkgqh->bqkgh", o).reshape(
             qb.shape[0], qb.shape[1], H, hd).astype(qb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b_entry, None, None, None),
                   P(b_entry, seq_axes, None, None),
@@ -489,7 +488,7 @@ def _attn_core(
     # base (prefill is per-request single-row, so its offset is scalar).
     per_row = jnp.ndim(causal_offset) == 1
     if seq_axes and Sq == 1 and Sk % max(
-            1, _mesh_prod(get_abstract_mesh(), seq_axes)) == 0:
+            1, _mesh_prod(jax.sharding.get_abstract_mesh(), seq_axes)) == 0:
         return _attn_decode_splitk(
             q, k, v, causal_offset=causal_offset, window=window,
             softcap=softcap, kv_len_mask=kv_len_mask, seq_axes=seq_axes)
@@ -655,7 +654,7 @@ def _vocab_parallel_gather(table: jax.Array, tokens: jax.Array) -> jax.Array:
     V = table.shape[0]
     if tp <= 1 or V % tp != 0:
         return jnp.take(table, tokens, axis=0)
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     vs = V // tp
     b_entry = batch_axes if batch_axes and tokens.shape[0] % _mesh_prod(
@@ -669,7 +668,7 @@ def _vocab_parallel_gather(table: jax.Array, tokens: jax.Array) -> jax.Array:
         out = jnp.where(mask, out, jnp.zeros((), out.dtype))
         return jax.lax.psum(out, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b_entry, None), P("model", None)),
         out_specs=P(b_entry, None, None),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel import constrain
-from repro.parallel.compat import get_abstract_mesh, shard_map
 
 from . import layers as L
 from .config import ModelConfig
@@ -141,7 +140,7 @@ def _combine(ye, flat_ids, position, tok_valid, S, k, cap):
         return _combine_local(ye.reshape(B, E * cap, d), flat_ids, position,
                               tok_valid, S, k, cap, 0, E)
 
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     bat = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     b_entry = bat if (bat and B % _prod(mesh, bat) == 0) else None
     e_local = E // tp
@@ -153,7 +152,7 @@ def _combine(ye, flat_ids, position, tok_valid, S, k, cap):
             fids, pos, tv, S, k, cap, e_lo, e_local)
         return jax.lax.psum(part, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b_entry, "model", None, None), P(b_entry, None),
                   P(b_entry, None), P(b_entry, None)),

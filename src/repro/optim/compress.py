@@ -78,8 +78,6 @@ def ring_allreduce_int8(x: jax.Array, mesh, axis: str = "pod"):
 
     spec = P(*(axis if i == 0 else None for i in range(max(x.ndim, 1))))
     del spec  # payload is replicated over `axis`; reduce in place
-    from repro.parallel.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
     )(x)

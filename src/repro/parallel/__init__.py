@@ -1,4 +1,3 @@
-from .compat import get_abstract_mesh, set_mesh, shard_map  # noqa: F401
 from .executor import execute_gemm, gemm_pspecs, use_mesh  # noqa: F401
 from .sharding import (  # noqa: F401
     act_batch_axes,

@@ -61,8 +61,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.blocking import round_up as _round_up
+from repro.core.winograd import PRECISION
 
-from .compat import shard_map
 from .strategy import MODES
 
 DATA_AXIS = "data"
@@ -173,6 +173,7 @@ def _padded_dims(mode, T: int, C: int, K: int, mesh):
 
 def _local_matmul(v, u):
     return jnp.einsum("ltc,lck->ltk", v, u,
+                      precision=PRECISION,
                       preferred_element_type=jnp.float32)
 
 
@@ -207,8 +208,8 @@ def execute_gemm(
             o = jax.lax.psum(o, psum_axis)
         return o
 
-    out = shard_map(local, mesh=mesh, in_specs=(v_spec, u_spec),
-                    out_specs=out_spec, check_vma=False)(V, U)
+    out = jax.shard_map(local, mesh=mesh, in_specs=(v_spec, u_spec),
+                        out_specs=out_spec, check_vma=False)(V, U)
     return out[:, :T, :K]
 
 

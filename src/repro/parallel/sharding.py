@@ -24,11 +24,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .compat import get_abstract_mesh
-
 
 def _active_mesh():
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return None
     return mesh

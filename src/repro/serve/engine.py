@@ -24,6 +24,7 @@ Sampling: greedy or temperature, always over the *real* vocab columns
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any
 
@@ -293,24 +294,39 @@ class ConvServeEngine:
             images = jnp.pad(images, ((0, pad),) + ((0, 0),) * (images.ndim - 1))
         return jax.device_put(images, NamedSharding(self.mesh, P("data")))
 
-    def infer(self, images: jax.Array) -> jax.Array:
-        """(B, H, W, C) -> logits; compiles once per input signature."""
-        B = images.shape[0]
-        if self.mesh is not None:
-            images = self._shard_batch(images)
+    def _jitted(self, images: jax.Array):
+        """The jitted forward for this input signature (one per signature)."""
         key = (tuple(images.shape), str(images.dtype))
         fn = self._compiled.get(key)
         if fn is None:
             fn = jax.jit(functools.partial(self.forward,
                                            algorithm=self.algorithm))
             self._compiled[key] = fn
-        if self.mesh is None:
-            return fn(self.params, images)
+        return fn
+
+    def _mesh_scope(self):
         from repro.parallel.executor import use_mesh
 
-        with use_mesh(self.mesh, self.parallel_mode):
-            out = fn(self.params, images)
+        return (use_mesh(self.mesh, self.parallel_mode)
+                if self.mesh is not None else contextlib.nullcontext())
+
+    def infer(self, images: jax.Array) -> jax.Array:
+        """(B, H, W, C) -> logits; compiles once per input signature."""
+        B = images.shape[0]
+        if self.mesh is not None:
+            images = self._shard_batch(images)
+        with self._mesh_scope():
+            out = self._jitted(images)(self.params, images)
         return out[:B] if out.shape[0] != B else out
+
+    def lower(self, images: jax.Array):
+        """The forward that ``infer`` runs for this batch, lowered the same
+        way (sharded and under the mesh scope) -- for inspecting what it
+        compiles to, e.g. ``engine.lower(x).compile().as_text()``."""
+        if self.mesh is not None:
+            images = self._shard_batch(images)
+        with self._mesh_scope():
+            return self._jitted(images).lower(self.params, images)
 
     @property
     def compiled_signatures(self) -> int:

@@ -84,18 +84,28 @@ def test_choose_blocks_e2e_infeasible_returns_none():
 
 def test_bwd_fused_blocks_fit_budget_on_table1_layers():
     """The fused-backward blocking model: for every Table-1 layer (and
-    both backward-relevant tile sizes) the chosen blocks' modeled VMEM is
-    within budget and the feasibility signal is sound."""
+    every tile size) the chosen blocks' modeled VMEM is within budget and
+    the feasibility signal is sound -- None only when even the smallest
+    blocks overflow the budget -- and the layer's planned m is feasible."""
+    from repro.core.plan import ConvSpec, plan
     from repro.models.cnn import TABLE1_LAYERS
 
     for spec in TABLE1_LAYERS:
+        planned = plan(ConvSpec(N=1, H=spec.H, W=spec.W, C=spec.C, K=spec.K,
+                                r=spec.r, pad=spec.pad)).m
         for m in (2, 4, 6):
             a = m + spec.r - 1
             L = a * a
             P = spec.H + 2 * spec.pad - spec.r + 1
             T = (-(-P // m)) ** 2
             cfg = blocking.choose_bwd_blocks(T, spec.C, spec.K, m, spec.r)
-            assert cfg is not None, (spec.name, m)
+            if cfg is None:
+                assert m != planned, (spec.name, m)
+                smallest = blocking.bwd_fused_vmem_bytes(
+                    L, m, round_up(spec.K, 8), 8, min(spec.C, 128),
+                    min(spec.K, 128), 4)
+                assert smallest > blocking.hw.VMEM_BUDGET, (spec.name, m)
+                continue
             Kp = round_up(spec.K, cfg.block_k)
             vm = blocking.bwd_fused_vmem_bytes(
                 L, m, Kp, cfg.block_t, cfg.block_c, cfg.block_k, 4)
@@ -126,7 +136,8 @@ def test_bwd_fused_traffic_strictly_below_two_pass_on_table1_layers():
             P = spec.H + 2 * spec.pad - spec.r + 1
             T = (-(-P // m)) ** 2
             cfg = blocking.choose_bwd_blocks(T, spec.C, spec.K, m, spec.r)
-            assert cfg is not None, (spec.name, m)
+            if cfg is None:  # no fused blocks fit: the two-pass runs (the
+                continue     # budget test above checks that signal)
             fused = blocking.hbm_traffic_bwd_fused(
                 L, m, T, spec.C, spec.K,
                 cfg.block_t, cfg.block_c, cfg.block_k, 4)
@@ -171,3 +182,95 @@ def test_e2e_traffic_below_fused_pipeline_pointwise():
         fused_pipeline = (hbm_traffic(L, m, T, C, K, bt, bk, 4, fused=True)
                           + blocking.transform_stage_bytes(L, T, C, 4))
         assert e2e < fused_pipeline, (T, C, K, m)
+
+
+# --------------------- scoped-VMEM limits (no compiler) ---------------------
+
+
+def _kernel_limits(monkeypatch, fn, *args):
+    """Trace ``fn(*args)`` with ``pallas_call`` replaced by a recorder:
+    returns [(kernel name, vmem_limit_bytes), ...] as the wrappers pass
+    them, without tracing a kernel body or running a compiler."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    seen = []
+
+    def recording_pallas_call(kernel, *, out_shape, name=None,
+                              compiler_params=None, **kw):
+        seen.append((name, compiler_params.vmem_limit_bytes))
+        return lambda *a: jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), out_shape)
+
+    # the jit trace caches would replay kernels traced by earlier tests
+    jax.clear_caches()
+    monkeypatch.setattr(pl, "pallas_call", recording_pallas_call)
+    try:
+        jax.eval_shape(fn, *args)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    return seen
+
+
+def test_kernel_vmem_limits_cover_model_on_table1_layers(monkeypatch):
+    """For every Table-1 layer and each pipeline the planner chooses from,
+    the scoped-VMEM limit every kernel of the pipeline passes is at least
+    the blocking model's bytes for its blocks and at most the v5e's VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.plan import ConvSpec, kernel_blocks, plan
+    from repro.kernels import ops
+    from repro.models.cnn import TABLE1_LAYERS
+
+    main_kernel = {"fused_e2e": "wino_fused_e2e", "fused": "wino_fused"}
+    for spec in TABLE1_LAYERS:
+        p = plan(ConvSpec(N=1, H=spec.H, W=spec.W, C=spec.C, K=spec.K,
+                          r=spec.r, pad=spec.pad))
+        m, a = p.m, p.m + spec.r - 1
+        T = (-(-(spec.H + 2 * spec.pad - spec.r + 1) // m)) ** 2
+        x = jax.ShapeDtypeStruct((1, spec.H, spec.W, spec.C), jnp.float32)
+        w = jax.ShapeDtypeStruct((spec.r, spec.r, spec.C, spec.K),
+                                 jnp.float32)
+        for pipeline, kernel in main_kernel.items():
+            cfg = kernel_blocks(T, spec.C, spec.K, m, spec.r, 4,
+                                pipeline=pipeline)
+            bt, bc, bk = cfg.block_t, cfg.block_c, cfg.block_k
+            model = {
+                kernel: cfg.vmem_bytes,
+                "filter_transform": blocking.streamed_vmem_bytes(
+                    4, (spec.r ** 2, bc, bk), (a * a, bc, bk)),
+                "input_transform": blocking.streamed_vmem_bytes(
+                    4, (bt, a * a, bc), (a * a, bt, bc)),
+            }
+            limits = dict(_kernel_limits(
+                monkeypatch,
+                lambda x, w: ops.conv2d_pallas(
+                    x, w, m=m, pad=spec.pad, pipeline=pipeline,
+                    interpret=False), x, w))
+            assert kernel in limits, (spec.name, pipeline, limits)
+            for name, limit in limits.items():
+                assert model[name] <= limit <= blocking.hw.VMEM_BYTES, \
+                    (spec.name, pipeline, name, model[name], limit)
+            assert cfg.vmem_bytes <= blocking.hw.VMEM_BUDGET
+
+
+def test_explicit_fused_e2e_that_cannot_fit_raises():
+    """An explicit fused_e2e request whose full-C V-cache cannot fit the
+    VMEM budget raises -- it no longer falls back to other blocks, which
+    the compiler would refuse on the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.plan import kernel_blocks
+    from repro.kernels import ops
+
+    with pytest.raises(ValueError, match="fused_e2e"):
+        kernel_blocks(512, 65536, 128, 6, 3, 4, pipeline="fused_e2e")
+    x = jax.ShapeDtypeStruct((1, 8, 8, 65536), jnp.float32)
+    w = jax.ShapeDtypeStruct((3, 3, 65536, 8), jnp.float32)
+    with pytest.raises(ValueError, match="fused_e2e"):
+        jax.eval_shape(lambda x, w: ops.conv2d_pallas(
+            x, w, m=6, pad=1, pipeline="fused_e2e", interpret=False), x, w)

@@ -37,7 +37,6 @@ import pytest
 from repro.configs.chatglm3_6b import SMOKE
 from repro.models import api as A
 from repro.models import layers as L
-from repro.parallel.compat import make_mesh, set_mesh
 from repro.serve.engine import ServeEngine
 from repro.serve.scheduler import ContinuousBatchingScheduler, Request
 
@@ -113,10 +112,11 @@ def _solo_streams(eng, reqs):
 
 def _run_mode(mode, monkeypatch, *, prefill_chunk=None, prompt_len=8):
     mesh_shape, cfg, slots = MODES[mode]
-    mesh = make_mesh(mesh_shape, ("data", "model"))
+    mesh = jax.make_mesh(mesh_shape, ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     api, params = _engine(cfg)
     spy = _Spy().install(monkeypatch)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         eng = ServeEngine(api, params, max_len=MAX_LEN)
         reqs = _ragged_requests(cfg, slots + 2, prompt_len=prompt_len)
         sched = ContinuousBatchingScheduler(eng, slots=slots,

@@ -122,7 +122,6 @@ from repro.models.api import build
 from repro.optim import adamw
 from repro.train import build_train_step, init_state
 from repro.parallel import specs as S
-from repro.parallel.compat import set_mesh
 from repro.launch.mesh import make_host_mesh
 from repro.data import SyntheticTokens
 
@@ -139,7 +138,7 @@ s0, m0 = jax.jit(step)(s0, batch)
 
 # 4x2 mesh with full sharding machinery
 mesh = make_host_mesh(dp=4, tp=2)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     s1 = init_state(api, opt, jax.random.PRNGKey(0))
     sh = S.state_shardings(jax.eval_shape(lambda: s1), mesh)
     b_sh = S.batch_shardings(batch, mesh)
@@ -154,7 +153,7 @@ np.testing.assert_allclose(np.asarray(w0), np.asarray(w1), atol=2e-4, rtol=2e-3)
 # serve path: decode on mesh == decode off mesh
 cache = api.init_cache(8, 40)
 lg, _ = api.prefill(s0.params, batch, cache)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     cache2 = api.init_cache(8, 40)
     lg2, _ = jax.jit(lambda p, b, c: api.prefill(p, b, c))(s1.params, batch, cache2)
 np.testing.assert_allclose(np.asarray(lg), np.asarray(lg2), atol=3e-3)

@@ -29,6 +29,16 @@ executor, with the backward-aware PartitionSpecs dual to the forward
 mode (DESIGN.md SS8) -- training never differentiates through shard_map.
 
 Eligibility for Winograd: square filter, r in {2,3,5...}, stride 1, groups 1.
+
+Every call names its operations for the profiler (``jax.named_scope``;
+metadata only, nothing runs differently): one scope per call,
+``conv_scope(x.shape, w.shape, stride)``, and below it one component
+naming the route the call resolved to -- the algorithm, or
+``winograd_sharded`` for the mesh path.  The Pallas and mesh pipelines
+name their stages below that (``repro.kernels.ops``), and
+``repro.models.cnn.conv_block`` puts its bias, affine and ReLU under the
+same conv scope in stage ``epilogue``.  A profiler trace then ties every
+device operation to its conv by the operation's name-scope path.
 """
 
 from __future__ import annotations
@@ -48,6 +58,18 @@ Algorithm = Literal[
 
 def winograd_eligible(w_shape: tuple, stride: int) -> bool:
     return eligible(w_shape[0], w_shape[1], stride)
+
+
+def conv_scope(x_shape: tuple, w_shape: tuple, stride: int) -> str:
+    """The name scope of one conv: ``conv_r{r}s{stride}_c{C}k{K}_h{H}w{W}``.
+
+    H and W are the input's spatial size before padding.  Convs of one
+    shape share the name, so their device time adds up under it: the unit
+    a plan is made for.
+    """
+    _, H, W, C = x_shape
+    r, _, _, K = w_shape
+    return f"conv_r{r}s{stride}_c{C}k{K}_h{H}w{W}"
 
 
 #: algorithms whose Winograd-domain GEMM the executor can shard.
@@ -99,36 +121,39 @@ def conv2d(
         if needs_mode:
             parallel_mode = p.parallel_mode
 
-    if mesh is not None and algorithm in _SHARDABLE and stride == 1:
-        from repro.kernels import ops  # deferred: keeps core importable w/o kernels
+    sharded = mesh is not None and algorithm in _SHARDABLE and stride == 1
+    with (jax.named_scope(conv_scope(x.shape, w.shape, stride)),
+          jax.named_scope("winograd_sharded" if sharded else algorithm)):
+        if sharded:
+            from repro.kernels import ops  # deferred: keeps core importable w/o kernels
 
-        if differentiable:
-            # custom VJP: dx and dw run under the backward-aware
-            # PartitionSpecs of the mode (never differentiate-through-
-            # shard_map; DESIGN.md SS8)
-            return ops.conv2d_sharded_ad(x, w, m, pad, mesh,
-                                         parallel_mode or "data")
-        return ops.conv2d_sharded(x, w, m=m, pad=pad, mesh=mesh,
-                                  mode=parallel_mode or "data")
+            if differentiable:
+                # custom VJP: dx and dw run under the backward-aware
+                # PartitionSpecs of the mode (never differentiate-through-
+                # shard_map; DESIGN.md SS8)
+                return ops.conv2d_sharded_ad(x, w, m, pad, mesh,
+                                             parallel_mode or "data")
+            return ops.conv2d_sharded(x, w, m=m, pad=pad, mesh=mesh,
+                                      mode=parallel_mode or "data")
 
-    if algorithm == "direct":
-        return wg.direct_conv2d(x, w, pad=pad, stride=stride)
+        if algorithm == "direct":
+            return wg.direct_conv2d(x, w, pad=pad, stride=stride)
 
-    assert stride == 1, f"{algorithm} requires stride 1"
-    if algorithm == "im2col":
-        return wg.im2col_conv2d(x, w, pad=pad)
-    if algorithm == "winograd":
-        return wg.winograd_conv2d_reference(x, w, m, pad=pad)
-    if algorithm == "winograd_tewmm":
-        return wg.winograd_conv2d_reference(x, w, m, pad=pad, use_tewmm=True)
-    if algorithm in ALGORITHM_PIPELINE:
-        from repro.kernels import ops  # deferred: keeps core importable w/o kernels
+        assert stride == 1, f"{algorithm} requires stride 1"
+        if algorithm == "im2col":
+            return wg.im2col_conv2d(x, w, pad=pad)
+        if algorithm == "winograd":
+            return wg.winograd_conv2d_reference(x, w, m, pad=pad)
+        if algorithm == "winograd_tewmm":
+            return wg.winograd_conv2d_reference(x, w, m, pad=pad, use_tewmm=True)
+        if algorithm in ALGORITHM_PIPELINE:
+            from repro.kernels import ops  # deferred: keeps core importable w/o kernels
 
-        pipeline = ALGORITHM_PIPELINE[algorithm]
-        if differentiable:
-            return ops.conv2d_pallas_ad(x, w, m, pad, pipeline)
-        return ops.conv2d_pallas(x, w, m=m, pad=pad, pipeline=pipeline)
-    raise ValueError(f"unknown algorithm {algorithm!r}")
+            pipeline = ALGORITHM_PIPELINE[algorithm]
+            if differentiable:
+                return ops.conv2d_pallas_ad(x, w, m, pad, pipeline)
+            return ops.conv2d_pallas(x, w, m=m, pad=pad, pipeline=pipeline)
+        raise ValueError(f"unknown algorithm {algorithm!r}")
 
 
 def conv1d(

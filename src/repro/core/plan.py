@@ -185,10 +185,6 @@ def plan_cache_info():
     return _plan.cache_info()
 
 
-def grad_plan_cache_info():
-    return _grad_plan.cache_info()
-
-
 def clear_plan_cache() -> None:
     _plan.cache_clear()
     _grad_plan.cache_clear()

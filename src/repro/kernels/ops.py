@@ -13,6 +13,12 @@ Three pipelines, mirroring the paper's comparison (DESIGN.md SS3):
     inverse-transform as separate HBM round trips), i.e. the structure of
     the libraries the paper beats.
 
+Each pipeline names its stages for the profiler (``jax.named_scope``,
+below the conv's own scope from ``repro.core.conv.conv2d``): ``tiles``
+(tile extraction), ``pad`` (padding to block multiples), ``filter`` (the
+filter transform), ``kernel`` (the transforms and the Winograd-domain
+GEMM) and ``crop`` (cropping and assembling the output).
+
 All consume the same extracted-tile layout; blocking comes from the
 ConvPlan layer (``repro.core.plan.kernel_blocks`` -- the single decision
 point).  Zero-padding of T/C/K up to block multiples replaces the paper's
@@ -112,14 +118,15 @@ def conv2d_pallas(
     # input storage rounding itself is benign.  Matches the reference
     # path's compute_dtype and the paper's fp32-throughout arithmetic.
     out_dtype = x.dtype
-    if x.dtype.itemsize < 4:
-        x, w = x.astype(jnp.float32), w.astype(jnp.float32)
+    with jax.named_scope("tiles"):
+        if x.dtype.itemsize < 4:
+            x, w = x.astype(jnp.float32), w.astype(jnp.float32)
 
-    # ---- tile extraction (OLA) ----
-    xp, tH, tW, P, Q = tiling.pad_for_tiles(x, m, r, pad)
-    d = tiling.flatten_tiles(tiling.extract_tiles(xp, m, r, tH, tW))
-    T = d.shape[0]
-    d = d.reshape(T, a * a, C)
+        # ---- tile extraction (OLA) ----
+        xp, tH, tW, P, Q = tiling.pad_for_tiles(x, m, r, pad)
+        d = tiling.flatten_tiles(tiling.extract_tiles(xp, m, r, tH, tW))
+        T = d.shape[0]
+        d = d.reshape(T, a * a, C)
 
     # ---- blocking (plan layer; paper SS3.2.2 analogue) ----
     from repro.core.plan import kernel_blocks  # deferred: keeps import acyclic
@@ -132,47 +139,51 @@ def conv2d_pallas(
             0, 0, 0,
         )
     Tp, Cp, Kp = _pad_dims(T, C, K, cfg)
-    d = common.pad_axis_to(common.pad_axis_to(d, 0, Tp), 2, Cp)
-    w_flat = w.reshape(r * r, C, K)
-    w_flat = common.pad_axis_to(common.pad_axis_to(w_flat, 1, Cp), 2, Kp)
+    with jax.named_scope("pad"):
+        d = common.pad_axis_to(common.pad_axis_to(d, 0, Tp), 2, Cp)
+        w_flat = w.reshape(r * r, C, K)
+        w_flat = common.pad_axis_to(common.pad_axis_to(w_flat, 1, Cp), 2, Kp)
 
     # ---- filter transform (packing fused in) ----
-    U = filter_transform(w_flat, m=m, r=r, block_c=cfg.block_c, block_k=cfg.block_k,
-                         interpret=interpret)
+    with jax.named_scope("filter"):
+        U = filter_transform(w_flat, m=m, r=r, block_c=cfg.block_c,
+                             block_k=cfg.block_k, interpret=interpret)
 
-    if pipeline == "fused_e2e":
-        # ---- single pass: transform prologue + GEMM + inverse epilogue ----
-        y = wino_fused_e2e(
-            d, U, m=m, r=r,
-            block_t=cfg.block_t, block_k=cfg.block_k, block_c=cfg.block_c,
-            interpret=interpret, out_dtype=out_dtype,
-        )
-    else:
-        # ---- input transform (separate HBM round trip for V) ----
-        V = input_transform(d, m=m, r=r, block_t=cfg.block_t, block_c=cfg.block_c,
-                            interpret=interpret)
-        # ---- GEMM (+ fused inverse transform) ----
-        if pipeline == "fused":
-            y = wino_fused(
-                V, U, m=m, r=r,
+    with jax.named_scope("kernel"):
+        if pipeline == "fused_e2e":
+            # ---- single pass: transform prologue + GEMM + inverse epilogue ----
+            y = wino_fused_e2e(
+                d, U, m=m, r=r,
                 block_t=cfg.block_t, block_k=cfg.block_k, block_c=cfg.block_c,
                 interpret=interpret, out_dtype=out_dtype,
             )
         else:
-            O_hat = wino_gemm(
-                V, U,
-                block_t=cfg.block_t, block_k=cfg.block_k, block_c=cfg.block_c,
-                interpret=interpret,
-            )
-            y = output_transform(
-                O_hat, m=m, r=r,
-                block_t=cfg.block_t, block_k=cfg.block_k,
-                interpret=interpret, out_dtype=out_dtype,
-            )
+            # ---- input transform (separate HBM round trip for V) ----
+            V = input_transform(d, m=m, r=r, block_t=cfg.block_t,
+                                block_c=cfg.block_c, interpret=interpret)
+            # ---- GEMM (+ fused inverse transform) ----
+            if pipeline == "fused":
+                y = wino_fused(
+                    V, U, m=m, r=r,
+                    block_t=cfg.block_t, block_k=cfg.block_k, block_c=cfg.block_c,
+                    interpret=interpret, out_dtype=out_dtype,
+                )
+            else:
+                O_hat = wino_gemm(
+                    V, U,
+                    block_t=cfg.block_t, block_k=cfg.block_k, block_c=cfg.block_c,
+                    interpret=interpret,
+                )
+                y = output_transform(
+                    O_hat, m=m, r=r,
+                    block_t=cfg.block_t, block_k=cfg.block_k,
+                    interpret=interpret, out_dtype=out_dtype,
+                )
 
     # ---- crop padding, assemble spatial output ----
-    y = y[:T, :, :K].reshape(T, m, m, K)
-    return tiling.assemble_output(y, N, tH, tW, P, Q)
+    with jax.named_scope("crop"):
+        y = y[:T, :, :K].reshape(T, m, m, K)
+        return tiling.assemble_output(y, N, tH, tW, P, Q)
 
 
 # ----------------------- sharded (mesh) pipeline -----------------------
@@ -205,15 +216,20 @@ def conv2d_sharded(
     r = w.shape[0]
     assert w.shape[0] == w.shape[1]
     in_dtype = x.dtype
-    x32, w32 = x.astype(jnp.float32), w.astype(jnp.float32)
-    N = x.shape[0]
-    xp, tH, tW, P, Q = tiling.pad_for_tiles(x32, m, r, pad)
-    d = tiling.flatten_tiles(tiling.extract_tiles(xp, m, r, tH, tW))
-    V = wg.input_transform(d, m, r)                    # (L, T, C)
-    U = wg.filter_transform(w32, m, r)                 # (L, C, K)
-    O_hat = execute_gemm(V, U, mode=mode, mesh=mesh)   # (L, T, K) f32
-    y = wg.output_transform(O_hat, m, r)               # (T, m, m, K)
-    return tiling.assemble_output(y, N, tH, tW, P, Q).astype(in_dtype)
+    with jax.named_scope("tiles"):
+        x32, w32 = x.astype(jnp.float32), w.astype(jnp.float32)
+        N = x.shape[0]
+        xp, tH, tW, P, Q = tiling.pad_for_tiles(x32, m, r, pad)
+        d = tiling.flatten_tiles(tiling.extract_tiles(xp, m, r, tH, tW))
+    with jax.named_scope("kernel"):
+        V = wg.input_transform(d, m, r)                    # (L, T, C)
+    with jax.named_scope("filter"):
+        U = wg.filter_transform(w32, m, r)                 # (L, C, K)
+    with jax.named_scope("kernel"):
+        O_hat = execute_gemm(V, U, mode=mode, mesh=mesh)   # (L, T, K) f32
+        y = wg.output_transform(O_hat, m, r)               # (T, m, m, K)
+    with jax.named_scope("crop"):
+        return tiling.assemble_output(y, N, tH, tW, P, Q).astype(in_dtype)
 
 
 # ------------------- differentiable sharded pipeline -------------------

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import conv2d
-from repro.core.conv import Algorithm
+from repro.core.conv import Algorithm, conv_scope
 from repro.core.winograd import PRECISION
 
 from .config import CNNConfig, ConvLayerSpec
@@ -42,11 +42,16 @@ def _affine_init(K: int) -> Params:
 
 def conv_block(p: Params, x: jax.Array, *, stride: int = 1, pad: int = 1,
                algorithm: Algorithm = "auto", act: bool = True) -> jax.Array:
+    """The planned conv, then bias, folded batch norm and ReLU.  The
+    epilogue is named under the conv's own scope (stage ``epilogue``), so a
+    fusion of it with the conv's last stage is charged to that conv."""
     y = conv2d(x, p["w"], stride=stride, pad=pad, algorithm=algorithm)
-    y = y + p["b"].astype(y.dtype)
-    if "affine" in p:
-        y = y * p["affine"]["scale"].astype(y.dtype) + p["affine"]["shift"].astype(y.dtype)
-    return jax.nn.relu(y) if act else y
+    with (jax.named_scope(conv_scope(x.shape, p["w"].shape, stride)),
+          jax.named_scope("epilogue")):
+        y = y + p["b"].astype(y.dtype)
+        if "affine" in p:
+            y = y * p["affine"]["scale"].astype(y.dtype) + p["affine"]["shift"].astype(y.dtype)
+        return jax.nn.relu(y) if act else y
 
 
 def maxpool2(x: jax.Array) -> jax.Array:

@@ -332,13 +332,6 @@ class ConvServeEngine:
     def compiled_signatures(self) -> int:
         return len(self._compiled)
 
-    @staticmethod
-    def plan_stats():
-        """Plan-cache hit counters -- the amortization this engine buys."""
-        from repro.core.plan import plan_cache_info
-
-        return plan_cache_info()
-
 
 class CoalescingConvServeEngine:
     """Request-coalescing front on ``ConvServeEngine``.

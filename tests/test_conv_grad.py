@@ -161,8 +161,8 @@ def test_grad_transforms_dual_structure():
 def test_grad_plan_cached_and_consistent():
     """GradPlan: cached like forward plans, dx plan is a forward plan for
     the rotated conv, ineligible shapes fall back to direct."""
-    from repro.core.plan import (ConvSpec, clear_plan_cache, grad_plan,
-                                 grad_plan_cache_info)
+    from repro.core.plan import (ConvSpec, _grad_plan, clear_plan_cache,
+                                 grad_plan)
 
     clear_plan_cache()
     spec = ConvSpec(N=1, H=28, W=28, C=64, K=64, r=3, pad=1)
@@ -171,7 +171,7 @@ def test_grad_plan_cached_and_consistent():
     assert gp.dw_blocks is not None
     assert gp.dx is not None and gp.dx.spec.C == spec.K and gp.dx.spec.K == spec.C
     gp2 = grad_plan(spec)
-    assert gp2 is gp and grad_plan_cache_info().hits >= 1
+    assert gp2 is gp and _grad_plan.cache_info().hits >= 1
     strided = ConvSpec(N=1, H=28, W=28, C=8, K=8, r=3, stride=2)
     assert grad_plan(strided).algorithm == "direct"
 

@@ -182,7 +182,7 @@ def test_conv_serve_engine_amortizes_plans():
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 20, 20, 4), jnp.float32)
 
     y1 = engine.infer(x)
-    hits_after_first = engine.plan_stats().hits
+    hits_after_first = plan_cache_info().hits
     y2 = engine.infer(x)                       # same signature: jit cache
     assert engine.compiled_signatures == 1
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2))
@@ -190,7 +190,7 @@ def test_conv_serve_engine_amortizes_plans():
     x2 = jax.random.normal(jax.random.PRNGKey(2), (2, 20, 20, 4), jnp.float32)
     engine.infer(x2)                           # new signature, same layers
     assert engine.compiled_signatures == 2
-    assert engine.plan_stats().hits >= hits_after_first
+    assert plan_cache_info().hits >= hits_after_first
 
     ref = forward(params, x, algorithm="winograd")
     np.testing.assert_allclose(np.asarray(y1), np.asarray(ref),

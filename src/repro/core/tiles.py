@@ -1,12 +1,20 @@
 """Tile extraction / output assembly for the overlap-add (OLA) Winograd scheme.
 
 The paper's transform kernels read overlapping (m+r-1)^2 input tiles straight
-from the strided NCHW image using register-reuse schedules (Fig. 2).  Pallas
-``BlockSpec``s cannot express overlapping HBM blocks, so on TPU we realize the
-same dataflow as an explicit *tile extraction* gather (XLA handles it as a
-copy/gather at HBM bandwidth), after which every kernel sees dense,
+from the strided NCHW image using register-reuse schedules (Fig. 2).  The
+fused kernels stream dense blocks, so on TPU we realize the same dataflow as
+an explicit *tile extraction* pass, after which every kernel sees dense,
 non-overlapping blocks.  This is the hardware adaptation recorded in
 DESIGN.md SS2/SS8; the r-1 halo duplication factor is (m+r-1)^2 / m^2.
+
+Extraction uses no gather.  Tile ``t`` along an axis covers padded rows
+``t*m ... t*m + alpha - 1``: the m rows of block ``t`` followed by the first
+``alpha - m`` rows of the next blocks.  So the padded image is reshaped into
+m-row blocks and each tile axis is built from ``ceil(alpha / m)`` shifted
+slices of whole blocks, concatenated along the within-block axis -- per axis,
+then one transpose of major axes.  This is the jnp form, for the reference,
+mesh and backward paths; the Pallas pipelines write the same tiles with the
+``kernels.tile_extract`` kernel in one pass.
 
 Layout convention is NHWC (TPU-native; channels map to the 128-wide lane
 dimension, exactly the role the paper gives its theta-channel vectors).
@@ -16,7 +24,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax import lax
 
 
 def num_tiles_1d(out_len: int, m: int) -> int:
@@ -27,38 +35,61 @@ def conv_out_len(in_len: int, r: int, pad: int) -> int:
     return in_len + 2 * pad - r + 1
 
 
+def tile_grid(H: int, W: int, m: int, r: int, pad: int) -> tuple[int, int, int, int]:
+    """(tH, tW, P, Q): the m x m output tiles covering the (P, Q) conv output."""
+    P = conv_out_len(H, r, pad)
+    Q = conv_out_len(W, r, pad)
+    return num_tiles_1d(P, m), num_tiles_1d(Q, m), P, Q
+
+
+def padded_len(t: int, m: int, r: int) -> int:
+    """Length of a padded axis holding ``t`` tiles as whole m-row blocks:
+    the last tile reaches ``ceil(alpha / m) - 1`` blocks past its own."""
+    return (t + -(-(m + r - 1) // m) - 1) * m
+
+
 def pad_for_tiles(x: jax.Array, m: int, r: int, pad: int) -> tuple[jax.Array, int, int, int, int]:
-    """Pad NHWC ``x`` so that (H,W) cover a whole number of m x m output tiles.
+    """Pad NHWC ``x`` so that (H,W) cover a whole number of m x m output tiles,
+    each padded axis a whole number of m-row blocks (``padded_len``).
 
     Returns (padded, tH, tW, P, Q) where (P, Q) is the true conv output size.
     """
     N, H, W, C = x.shape
-    P = conv_out_len(H, r, pad)
-    Q = conv_out_len(W, r, pad)
-    tH = num_tiles_1d(P, m)
-    tW = num_tiles_1d(Q, m)
-    alpha = m + r - 1
-    want_h = tH * m + r - 1
-    want_w = tW * m + r - 1
+    tH, tW, P, Q = tile_grid(H, W, m, r, pad)
     x = jnp.pad(
         x,
-        ((0, 0), (pad, want_h - H - pad), (pad, want_w - W - pad), (0, 0)),
+        ((0, 0), (pad, padded_len(tH, m, r) - H - pad),
+         (pad, padded_len(tW, m, r) - W - pad), (0, 0)),
     )
-    del alpha
     return x, tH, tW, P, Q
 
 
-def extract_tiles(x_padded: jax.Array, m: int, r: int, tH: int, tW: int) -> jax.Array:
-    """(N, H', W', C) -> (N, tH, tW, alpha, alpha, C) overlapping tile gather."""
+def _tile_axis(x: jax.Array, axis: int, m: int, r: int, t: int) -> jax.Array:
+    """Split ``axis`` into (t, alpha): tile i holds entries i*m ... i*m+alpha-1.
+
+    The axis holds at least ``padded_len`` entries, as ``pad_for_tiles``
+    gives; it is cut to that and split into m-row blocks, and tile i's alpha
+    rows are blocks i, i+1, ... concatenated, the last one cut short.
+    """
     alpha = m + r - 1
-    idx_h = np.arange(tH)[:, None] * m + np.arange(alpha)[None, :]  # (tH, alpha)
-    idx_w = np.arange(tW)[:, None] * m + np.arange(alpha)[None, :]  # (tW, alpha)
-    # gather rows then cols; XLA lowers these to efficient gathers/copies
-    x = jnp.take(x_padded, jnp.asarray(idx_h.reshape(-1)), axis=1)
-    x = x.reshape(x.shape[0], tH, alpha, *x.shape[2:])  # (N,tH,alpha,W',C)
-    x = jnp.take(x, jnp.asarray(idx_w.reshape(-1)), axis=3)
-    x = x.reshape(x.shape[0], tH, alpha, tW, alpha, x.shape[-1])
-    return jnp.transpose(x, (0, 1, 3, 2, 4, 5))  # (N,tH,tW,alpha,alpha,C)
+    nb = -(-alpha // m)
+    n = padded_len(t, m, r)
+    assert x.shape[axis] >= n, (x.shape, axis, n)
+    x = lax.slice_in_dim(x, 0, n, axis=axis)
+    x = x.reshape(*x.shape[:axis], t + nb - 1, m, *x.shape[axis + 1:])
+    parts = [lax.slice_in_dim(x, j, j + t, axis=axis) for j in range(nb)]
+    parts[-1] = lax.slice_in_dim(parts[-1], 0, alpha - (nb - 1) * m, axis=axis + 1)
+    return jnp.concatenate(parts, axis=axis + 1)
+
+
+def extract_tiles(x_padded: jax.Array, m: int, r: int, tH: int, tW: int) -> jax.Array:
+    """(N, H', W', C) -> (N, tH, tW, alpha, alpha, C) overlapping tiles.
+
+    Contiguous block slices per axis (module docstring), no gather.
+    """
+    x = _tile_axis(x_padded, 1, m, r, tH)        # (N, tH, alpha, W', C)
+    x = _tile_axis(x, 3, m, r, tW)               # (N, tH, alpha, tW, alpha, C)
+    return jnp.transpose(x, (0, 1, 3, 2, 4, 5))  # (N, tH, tW, alpha, alpha, C)
 
 
 def flatten_tiles(tiles: jax.Array) -> jax.Array:
@@ -96,23 +127,22 @@ def overlap_add_tiles(dd: jax.Array, N: int, tH: int, tW: int, m: int, r: int,
     """(T, a, a, C) -> (N, H, W, C): the exact adjoint of ``pad_for_tiles``
     + ``extract_tiles`` + ``flatten_tiles``.
 
-    Overlapping tiles scatter-ADD back onto the padded image (each padded
-    pixel is read by up to ceil(a/m)^2 tiles forward, so its gradient is
-    the sum of those tiles' contributions), then the pad border is cropped
-    (adjoint of zero-padding).  Realized with ``jax.linear_transpose`` over
-    the take-based gather, which XLA lowers to the dual scatter-add -- one
-    definition, provably the transpose of the forward extraction.
+    Overlapping tiles ADD back onto the padded image (each padded pixel is
+    read by up to ceil(a/m)^2 tiles forward, so its gradient is the sum of
+    those tiles' contributions), then the pad border is cropped (adjoint of
+    zero-padding).  Realized with ``jax.linear_transpose`` over the
+    extraction: the slices and concatenates transpose to pads and adds, no
+    scatter -- one definition, provably the transpose of the forward.
     """
     a = m + r - 1
     C = dd.shape[-1]
-    Hp = tH * m + r - 1
-    Wp = tW * m + r - 1
 
-    def _gather(xp):
+    def _extract(xp):
         return flatten_tiles(extract_tiles(xp, m, r, tH, tW))
 
-    xp_shape = jax.ShapeDtypeStruct((N, Hp, Wp, C), dd.dtype)
-    (dxp,) = jax.linear_transpose(_gather, xp_shape)(dd.reshape(-1, a, a, C))
+    xp_shape = jax.ShapeDtypeStruct(
+        (N, padded_len(tH, m, r), padded_len(tW, m, r), C), dd.dtype)
+    (dxp,) = jax.linear_transpose(_extract, xp_shape)(dd.reshape(-1, a, a, C))
     return dxp[:, pad:pad + H, pad:pad + W, :]
 
 
@@ -122,15 +152,11 @@ def overlap_add_tiles(dd: jax.Array, N: int, tH: int, tW: int, m: int, r: int,
 
 def pad_for_tiles_1d(x: jax.Array, m: int, r: int, pad: int) -> tuple[jax.Array, int, int]:
     N, Tlen, C = x.shape
-    P = Tlen + 2 * pad - r + 1
+    P = conv_out_len(Tlen, r, pad)
     t = num_tiles_1d(P, m)
-    want = t * m + r - 1
-    x = jnp.pad(x, ((0, 0), (pad, want - Tlen - pad), (0, 0)))
+    x = jnp.pad(x, ((0, 0), (pad, padded_len(t, m, r) - Tlen - pad), (0, 0)))
     return x, t, P
 
 
 def extract_tiles_1d(x_padded: jax.Array, m: int, r: int, t: int) -> jax.Array:
-    alpha = m + r - 1
-    idx = np.arange(t)[:, None] * m + np.arange(alpha)[None, :]
-    x = jnp.take(x_padded, jnp.asarray(idx.reshape(-1)), axis=1)
-    return x.reshape(x.shape[0], t, alpha, x.shape[-1])  # (N, t, alpha, C)
+    return _tile_axis(x_padded, 1, m, r, t)  # (N, t, alpha, C)

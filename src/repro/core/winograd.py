@@ -177,7 +177,7 @@ def output_transform_adjoint(gy_tiles: jax.Array, m: int, r: int) -> jax.Array:
 def input_transform_adjoint(dV: jax.Array, m: int, r: int) -> jax.Array:
     """dV (L, T, C) -> dd (T, a, a, C): the transpose of ``input_transform``.
 
-    dd = B dV B^T; the overlap-add scatter back onto the image
+    dd = B dV B^T; the overlap-add back onto the image
     (``tiles.overlap_add_tiles``) completes dL/dx.
     """
     _, _, BT = _consts(m, r, dV.dtype)

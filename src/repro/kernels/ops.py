@@ -15,9 +15,10 @@ Three pipelines, mirroring the paper's comparison (DESIGN.md SS3):
 
 Each pipeline names its stages for the profiler (``jax.named_scope``,
 below the conv's own scope from ``repro.core.conv.conv2d``): ``tiles``
-(tile extraction), ``pad`` (padding to block multiples), ``filter`` (the
-filter transform), ``kernel`` (the transforms and the Winograd-domain
-GEMM) and ``crop`` (cropping and assembling the output).
+(tile extraction, ``d`` padded to block multiples as it is written),
+``pad`` (the filter padded to block multiples), ``filter`` (the filter
+transform), ``kernel`` (the transforms and the Winograd-domain GEMM) and
+``crop`` (cropping and assembling the output).
 
 All consume the same extracted-tile layout; blocking comes from the
 ConvPlan layer (``repro.core.plan.kernel_blocks`` -- the single decision
@@ -43,6 +44,7 @@ from .filter_transform import filter_transform
 from .grad_transform import grad_output_transform
 from .input_transform import input_transform
 from .output_transform import output_transform
+from .tile_extract import tile_extract
 from .wino_fused import wino_fused
 from .wino_fused_bwd import wino_fused_bwd
 from .wino_fused_e2e import wino_fused_e2e
@@ -55,6 +57,21 @@ def _pad_dims(T: int, C: int, K: int, cfg: BlockConfig) -> tuple[int, int, int]:
         round_up(C, cfg.block_c),
         round_up(K, cfg.block_k),
     )
+
+
+def _tile_matrix(x: jax.Array, m: int, r: int, pad: int, Tp: int, Cp: int,
+                 interpret: bool | None) -> jax.Array:
+    """x (N, H, W, C) -> d (Tp, alpha^2, Cp): the kernels' input tiles,
+    zero past the T tiles and the C channels.
+
+    C is zero-padded on the image, in the same pass as its spatial
+    padding, where it costs a tile's (m/alpha)^2 share of padding ``d``.
+    The ``tile_extract`` kernel then writes ``d`` in one pass, T padding
+    included.
+    """
+    x = common.pad_axis_to(x, 3, Cp)
+    xp, tH, tW, _, _ = tiling.pad_for_tiles(x, m, r, pad)
+    return tile_extract(xp, m=m, r=r, tH=tH, tW=tW, Tp=Tp, interpret=interpret)
 
 
 # Trace-time switch routing custom-VJP backwards through the PR-3 two-pass
@@ -108,7 +125,6 @@ def conv2d_pallas(
     assert pipeline in PIPELINES, pipeline
     r = w.shape[0]
     assert w.shape[0] == w.shape[1]
-    a = m + r - 1
     N, H, W, C = x.shape
     K = w.shape[-1]
 
@@ -118,20 +134,13 @@ def conv2d_pallas(
     # input storage rounding itself is benign.  Matches the reference
     # path's compute_dtype and the paper's fp32-throughout arithmetic.
     out_dtype = x.dtype
-    with jax.named_scope("tiles"):
-        if x.dtype.itemsize < 4:
-            x, w = x.astype(jnp.float32), w.astype(jnp.float32)
-
-        # ---- tile extraction (OLA) ----
-        xp, tH, tW, P, Q = tiling.pad_for_tiles(x, m, r, pad)
-        d = tiling.flatten_tiles(tiling.extract_tiles(xp, m, r, tH, tW))
-        T = d.shape[0]
-        d = d.reshape(T, a * a, C)
+    tH, tW, P, Q = tiling.tile_grid(H, W, m, r, pad)
+    T = N * tH * tW
 
     # ---- blocking (plan layer; paper SS3.2.2 analogue) ----
     from repro.core.plan import kernel_blocks  # deferred: keeps import acyclic
 
-    elt = x.dtype.itemsize
+    elt = max(x.dtype.itemsize, 4)
     cfg = kernel_blocks(T, C, K, m, r, elt, pipeline=pipeline)
     if block_t is not None or block_c is not None or block_k is not None:
         cfg = BlockConfig(
@@ -139,8 +148,14 @@ def conv2d_pallas(
             0, 0, 0,
         )
     Tp, Cp, Kp = _pad_dims(T, C, K, cfg)
+
+    with jax.named_scope("tiles"):
+        if x.dtype.itemsize < 4:
+            x, w = x.astype(jnp.float32), w.astype(jnp.float32)
+
+        # ---- tile extraction (OLA), padded to (Tp, a*a, Cp) ----
+        d = _tile_matrix(x, m, r, pad, Tp, Cp, interpret)
     with jax.named_scope("pad"):
-        d = common.pad_axis_to(common.pad_axis_to(d, 0, Tp), 2, Cp)
         w_flat = w.reshape(r * r, C, K)
         w_flat = common.pad_axis_to(common.pad_axis_to(w_flat, 1, Cp), 2, Kp)
 
@@ -369,24 +384,21 @@ def conv2d_filter_grad(
 
     x = x.astype(jnp.float32)
     gy = gy.astype(jnp.float32)
-    a = m + r - 1
     N, H, W, C = x.shape
     K = gy.shape[-1]
-
-    # ---- tiling: overlapping x tiles + non-overlapping gy tiles ----
-    xp, tH, tW, P, Q = tiling.pad_for_tiles(x, m, r, pad)
-    d = tiling.flatten_tiles(tiling.extract_tiles(xp, m, r, tH, tW))
-    T = d.shape[0]
-    d = d.reshape(T, a * a, C)
-    gy_t = tiling.extract_output_tiles(gy, m, tH, tW)    # (T, m, m, K)
-    gy_t = gy_t.reshape(T, m * m, K)
+    tH, tW, _, _ = tiling.tile_grid(H, W, m, r, pad)
+    T = N * tH * tW
 
     # ---- blocking (plan layer): rows=C, contraction=T, cols=K ----
     cfg = grad_kernel_blocks(C, T, K, m, r, elt_bytes=4)
     Cp = round_up(C, cfg.block_t)
     Tp = round_up(T, cfg.block_c)
     Kp = round_up(K, cfg.block_k)
-    d = common.pad_axis_to(common.pad_axis_to(d, 0, Tp), 2, Cp)
+
+    # ---- tiling: overlapping x tiles + non-overlapping gy tiles ----
+    d = _tile_matrix(x, m, r, pad, Tp, Cp, interpret)
+    gy_t = tiling.extract_output_tiles(gy, m, tH, tW)    # (T, m, m, K)
+    gy_t = gy_t.reshape(T, m * m, K)
     gy_t = common.pad_axis_to(common.pad_axis_to(gy_t, 0, Tp), 2, Kp)
 
     # ---- transforms (Pallas): X~ = B^T d B, Gy = G' gy G'^T ----
@@ -424,11 +436,10 @@ def fused_bwd_eligible(x_shape, w_shape, m: int, pad: int) -> bool:
     N, H, W, C = x_shape
     r = int(w_shape[0])
     K = int(w_shape[-1])
-    P = H + 2 * pad - r + 1
-    Q = W + 2 * pad - r + 1
+    tH, tW, P, Q = tiling.tile_grid(H, W, m, r, pad)
     if P < 1 or Q < 1:
         return False
-    T = N * tiling.num_tiles_1d(P, m) * tiling.num_tiles_1d(Q, m)
+    T = N * tH * tW
     return bwd_kernel_blocks(T, C, K, m, r) is not None
 
 
@@ -457,19 +468,17 @@ def conv2d_fused_bwd(
     K = w.shape[-1]
     x32 = x.astype(jnp.float32)
     gy32 = gy.astype(jnp.float32)
-
-    # ---- tiling: overlapping x tiles + non-overlapping gy tiles ----
-    xp, tH, tW, P, Q = tiling.pad_for_tiles(x32, m, r, pad)
-    d = tiling.flatten_tiles(tiling.extract_tiles(xp, m, r, tH, tW))
-    T = d.shape[0]
-    d = d.reshape(T, a * a, C)
-    gy_t = tiling.extract_output_tiles(gy32, m, tH, tW).reshape(T, m * m, K)
+    tH, tW, _, _ = tiling.tile_grid(H, W, m, r, pad)
+    T = N * tH * tW
 
     # ---- blocking (plan layer): the fused-backward model ----
     cfg = bwd_kernel_blocks(T, C, K, m, r)
     assert cfg is not None, "check fused_bwd_eligible before calling"
     Tp, Cp, Kp = _pad_dims(T, C, K, cfg)
-    d = common.pad_axis_to(common.pad_axis_to(d, 0, Tp), 2, Cp)
+
+    # ---- tiling: overlapping x tiles + non-overlapping gy tiles ----
+    d = _tile_matrix(x32, m, r, pad, Tp, Cp, interpret)
+    gy_t = tiling.extract_output_tiles(gy32, m, tH, tW).reshape(T, m * m, K)
     gy_t = common.pad_axis_to(common.pad_axis_to(gy_t, 0, Tp), 2, Kp)
     w_flat = w.astype(jnp.float32).reshape(r * r, C, K)
     w_flat = common.pad_axis_to(common.pad_axis_to(w_flat, 1, Cp), 2, Kp)
@@ -481,7 +490,7 @@ def conv2d_fused_bwd(
         d, gy_t, U, m=m, r=r, block_t=cfg.block_t, block_c=cfg.block_c,
         block_k=cfg.block_k, interpret=interpret)
 
-    # ---- epilogues outside the kernel: OLA scatter-add + r x r inverse ----
+    # ---- epilogues outside the kernel: OLA add + r x r inverse ----
     dd = dd[:T, :, :C].reshape(T, a, a, C)
     dx = tiling.overlap_add_tiles(dd, N, tH, tW, m, r, H, W, pad)
     dw = wg.filter_transform_adjoint(dU[:, :C, :K], m, r)

@@ -221,8 +221,9 @@ def test_kernel_vmem_limits_cover_model_on_table1_layers(monkeypatch):
     import jax
     import jax.numpy as jnp
 
+    from repro.core import tiles
     from repro.core.plan import ConvSpec, kernel_blocks, plan
-    from repro.kernels import ops
+    from repro.kernels import ops, tile_extract
     from repro.models.cnn import TABLE1_LAYERS
 
     main_kernel = {"fused_e2e": "wino_fused_e2e", "fused": "wino_fused"}
@@ -230,7 +231,8 @@ def test_kernel_vmem_limits_cover_model_on_table1_layers(monkeypatch):
         p = plan(ConvSpec(N=1, H=spec.H, W=spec.W, C=spec.C, K=spec.K,
                           r=spec.r, pad=spec.pad))
         m, a = p.m, p.m + spec.r - 1
-        T = (-(-(spec.H + 2 * spec.pad - spec.r + 1) // m)) ** 2
+        tH, tW, _, _ = tiles.tile_grid(spec.H, spec.W, m, spec.r, spec.pad)
+        T = tH * tW
         x = jax.ShapeDtypeStruct((1, spec.H, spec.W, spec.C), jnp.float32)
         w = jax.ShapeDtypeStruct((spec.r, spec.r, spec.C, spec.K),
                                  jnp.float32)
@@ -244,6 +246,10 @@ def test_kernel_vmem_limits_cover_model_on_table1_layers(monkeypatch):
                     4, (spec.r ** 2, bc, bk), (a * a, bc, bk)),
                 "input_transform": blocking.streamed_vmem_bytes(
                     4, (bt, a * a, bc), (a * a, bt, bc)),
+                "tile_extract": blocking.streamed_vmem_bytes(
+                    4, *tile_extract.step_blocks(
+                        1, tH, tW, m, spec.r, tiles.padded_len(tW, m, spec.r),
+                        -(-spec.C // bc) * bc, 4)),
             }
             limits = dict(_kernel_limits(
                 monkeypatch,

@@ -100,6 +100,8 @@ def _xw(N, H, W, C, K, r=3, seed=0):
             jax.random.normal(kw, (r, r, C, K), jnp.float32))
 
 
+# Six channels, padded to eight, so the filter has a ``pad`` stage: the
+# input tiles come out of ``tiles`` already padded.
 PIPELINE_STAGES = {"tiles", "pad", "filter", "kernel", "crop"}
 SHARDED_STAGES = {"tiles", "filter", "kernel", "crop"}
 
@@ -115,10 +117,10 @@ ROUTES = [
 @pytest.mark.parametrize("algorithm,stride,stages", ROUTES,
                          ids=[f"{a}-s{s}" for a, s, _ in ROUTES])
 def test_conv2d_names_its_conv_route_and_stages(algorithm, stride, stages):
-    x, w = _xw(1, 10, 12, 8, 16)
+    x, w = _xw(1, 10, 12, 6, 16)
     check_scopes(
         lambda x, w: conv2d(x, w, stride=stride, pad=1, algorithm=algorithm),
-        (x, w), (3, stride, 8, 16, 10, 12), algorithm, stages)
+        (x, w), (3, stride, 6, 16, 10, 12), algorithm, stages)
 
 
 @pytest.mark.parametrize("differentiable", [True, False],
@@ -134,13 +136,13 @@ def test_mesh_forward_names_its_conv_route_and_stages(differentiable):
 
 
 def test_conv_block_charges_its_epilogue_to_the_conv():
-    p = {**cnn._conv_init(jax.random.PRNGKey(0), 3, 8, 16),
+    p = {**cnn._conv_init(jax.random.PRNGKey(0), 3, 6, 16),
          "affine": {"scale": jnp.full((16,), 1.5), "shift": jnp.ones((16,))}}
-    x, _ = _xw(1, 10, 10, 8, 16)
+    x, _ = _xw(1, 10, 10, 6, 16)
     check_scopes(
         lambda p, x: cnn.conv_block(p, x, pad=1,
                                     algorithm="winograd_fused_e2e"),
-        (p, x), (3, 1, 8, 16, 10, 10), "winograd_fused_e2e",
+        (p, x), (3, 1, 6, 16, 10, 10), "winograd_fused_e2e",
         PIPELINE_STAGES | {"epilogue"})
 
 

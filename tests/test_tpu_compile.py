@@ -13,6 +13,7 @@ collection): only the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +83,24 @@ def test_fused_e2e_compiles_for_v5e(one_chip, name, m):
     calls = tpu_kernel_calls(hlo)
     assert calls["wino_fused_e2e"] == 1 and calls["filter_transform"] == 1, \
         calls
+    assert calls["tile_extract"] == 1 and " gather(" not in hlo, calls
+
+
+@pytest.mark.parametrize("N,H,W,C,m", [(2, 20, 17, 64, 6), (2, 224, 224, 3, 4),
+                                       (2, 14, 14, 256, 4), (2, 7, 7, 512, 2)])
+def test_tile_stage_compiles_without_gather_for_v5e(one_chip, N, H, W, C, m):
+    """The input tiles come from the ``tile_extract`` kernel, padded to the
+    block multiples by the kernel itself: no gather, no pad of ``d``."""
+    x = jax.ShapeDtypeStruct((N, H, W, C), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, 3, C, 64), jnp.float32, sharding=one_chip)
+    hlo = ops.conv2d_pallas.lower(
+        x, w, m=m, pad=1, pipeline="fused_e2e",
+        interpret=False).compile().as_text()
+    assert " gather(" not in hlo
+    assert tpu_kernel_calls(hlo)["tile_extract"] == 1
+    # d goes from the extraction kernel straight into the Winograd kernel
+    assert re.search(r"%wino_fused_e2e[.\d]* = [^\n]*"
+                     r"custom-call\(%tile_extract[.\d]*,", hlo), hlo[-3000:]
 
 
 @pytest.mark.parametrize("pipeline,kernels", [
